@@ -8,9 +8,11 @@ path (``pip install -e .`` or ``PYTHONPATH=src``):
 from __future__ import annotations
 
 import argparse
+from pathlib import Path
 
 from benchmarks import (async_ckpt, degraded, kernel_bench, paper_figures,
                         pipeline, restore, rounds, spmd_bytes, transport)
+from repro.launch.cache import enable_compilation_cache
 
 SUITES = {
     "fig2": paper_figures.fig2_congestion,
@@ -34,6 +36,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compilation_cache(Path(__file__).resolve().parents[1])
     print("name,us_per_call,derived")
     for name, fn in SUITES.items():
         if args.only and args.only != name:

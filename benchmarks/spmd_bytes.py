@@ -5,7 +5,10 @@ Lowers both SPMD collective-write schedules for an 8-device
 wire bytes per collective kind. derived = TAM/two-phase byte ratio on
 the slow ('node') axis proxy (all_to_all + node-axis gathers).
 
-Run in a subprocess (needs its own XLA device count).
+The counts come from the CPU backend's HLO on 8 virtual devices, so the
+child runs with ``JAX_PLATFORMS=cpu`` (it never reaches for a chip the
+parent may hold) and every row is labelled ``spmd_bytes/cpu_hlo/...``.
+They are byte counts, not device measurements.
 """
 from __future__ import annotations
 
@@ -20,8 +23,9 @@ import json
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import IOConfig, contiguous_layout, make_tam_write, make_twophase_write
 from repro.launch.hlo_analysis import HloCostModel
+from repro.launch.mesh import make_io_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("node", "lagg", "lmem"))
+mesh = make_io_mesh(2, 2, 2)
 layout = contiguous_layout(4096, 2)
 # the paper's regime: request METADATA dominates payload (E3SM-F: 1.4e9
 # tiny requests for 14 GiB). 256 adjacent 1-element requests per rank
@@ -52,6 +56,7 @@ def collective_bytes():
     import os
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
@@ -60,9 +65,9 @@ def collective_bytes():
     rows = []
     for name, kinds in data.items():
         for kind, v in sorted(kinds.items()):
-            rows.append((f"spmd_bytes/{name}/{kind}", 0.0, int(v)))
+            rows.append((f"spmd_bytes/cpu_hlo/{name}/{kind}", 0.0, int(v)))
     tot_tam = sum(data["tam"].values())
     tot_2ph = sum(data["twophase"].values())
-    rows.append(("spmd_bytes/tam_over_twophase_total", 0.0,
+    rows.append(("spmd_bytes/cpu_hlo/tam_over_twophase_total", 0.0,
                  round(tot_tam / max(tot_2ph, 1), 3)))
     return rows

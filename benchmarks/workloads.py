@@ -9,6 +9,7 @@ the e3sm_f / e3sm_g / btio / s3d parameter tables independently.
 from __future__ import annotations
 
 from repro.core import cost_model as cm
+from repro.core.requests import ELEM_BYTES
 from repro.io_patterns import (btio_pattern, e3sm_f_pattern,
                                e3sm_g_pattern, s3d_pattern,
                                sparse_checkpoint_pattern)
@@ -41,3 +42,13 @@ def paper_workload(name: str, P: int = PAPER_P,
                    nodes: int = PAPER_NODES) -> cm.Workload:
     """The named Table I workload at (P, nodes) — paper scale default."""
     return MODEL_WORKLOADS[name](P, nodes)
+
+
+def node_share(name: str) -> tuple[int, int]:
+    """One node's share of the named Table I workload at paper scale:
+    ``(requests, bytes per request)``, the request size rounded down
+    to whole 4-byte elements (E3SM-G: 679,688 requests of 524 B)."""
+    w = paper_workload(name)
+    n_req = w.k * w.P
+    req_bytes = int(w.total_bytes / n_req) // ELEM_BYTES * ELEM_BYTES
+    return round(n_req / w.nodes), req_bytes

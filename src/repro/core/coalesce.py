@@ -17,7 +17,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.requests import PAD_OFFSET, RequestList, mask_invalid
+from repro.core.requests import (PAD_OFFSET, RequestList,
+                                 exclusive_prefix_sum, mask_invalid,
+                                 prefix_sum, segment_ids)
 
 
 def sort_requests(r: RequestList) -> RequestList:
@@ -60,7 +62,7 @@ def coalesce_sorted(r: RequestList) -> RequestList:
     # a new segment starts where the request is not contiguous with the
     # previous one; padding always starts its own (discarded) segment.
     boundary = (off != prev_end) | is_pad
-    seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    seg = prefix_sum(boundary) - 1
     seg_off = jax.ops.segment_min(jnp.where(is_pad, PAD_OFFSET, off), seg,
                                   num_segments=cap)
     seg_len = jax.ops.segment_sum(jnp.where(is_pad, 0, ln), seg,
@@ -103,16 +105,14 @@ def pack_data(r: RequestList, starts: jax.Array, data: jax.Array,
     Elements mapping outside [0, out_len) are dropped — that is how a
     device ignores requests outside its file domain.
     """
-    cap = r.capacity
     dcap = data.shape[0]
     # walk a contiguous "element stream": element e belongs to request
     # req_of[e] at index `within` inside that request. Its source lives at
     # starts[req] + within in `data` (slab gaps allowed); its destination
     # is offsets[req] + within - base in the output buffer.
-    req_of = jnp.repeat(jnp.arange(cap, dtype=jnp.int32), r.lengths,
-                        total_repeat_length=dcap)
+    req_of = segment_ids(r.lengths, dcap)
     eidx = jnp.arange(dcap, dtype=jnp.int32)
-    packed_starts = (jnp.cumsum(r.lengths) - r.lengths).astype(jnp.int32)
+    packed_starts = exclusive_prefix_sum(r.lengths)
     within = eidx - packed_starts[req_of]
     src = starts[req_of] + within
     dst = r.offsets[req_of] + within - base
@@ -128,9 +128,7 @@ def pack_data(r: RequestList, starts: jax.Array, data: jax.Array,
 def unpack_data(r: RequestList, starts: jax.Array, buf: jax.Array,
                 out_len: int, base: jax.Array | int = 0) -> jax.Array:
     """Gather request payloads out of a contiguous buffer (read path)."""
-    cap = r.capacity
-    req_of = jnp.repeat(jnp.arange(cap, dtype=jnp.int32), r.lengths,
-                        total_repeat_length=out_len)
+    req_of = segment_ids(r.lengths, out_len)
     within = jnp.arange(out_len, dtype=jnp.int32) - starts[req_of]
     pos = r.offsets[req_of] + within - base
     total = jnp.sum(r.lengths, dtype=jnp.int32)
@@ -141,5 +139,4 @@ def unpack_data(r: RequestList, starts: jax.Array, buf: jax.Array,
 
 def request_starts(r: RequestList) -> jax.Array:
     """Start position of each request's payload in the packed data buffer."""
-    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                            jnp.cumsum(r.lengths)[:-1].astype(jnp.int32)])
+    return exclusive_prefix_sum(r.lengths)

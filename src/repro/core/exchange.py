@@ -17,7 +17,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.requests import PAD_OFFSET, RequestList, mask_invalid
+from repro.core.requests import (PAD_OFFSET, RequestList,
+                                 exclusive_prefix_sum, mask_invalid,
+                                 segment_ids)
 
 
 class Buckets(NamedTuple):
@@ -39,10 +41,6 @@ class Buckets(NamedTuple):
     data: jax.Array
     dropped_requests: jax.Array
     dropped_elems: jax.Array
-
-
-def _exclusive_cumsum(x: jax.Array) -> jax.Array:
-    return jnp.concatenate([jnp.zeros((1,), x.dtype), jnp.cumsum(x)[:-1]])
 
 
 def sort_with(r: RequestList, *extras: jax.Array):
@@ -77,7 +75,7 @@ def bucket_by_dest(r: RequestList, starts: jax.Array, data: jax.Array,
     go, gl, gd = r.offsets[order], r.lengths[order], d[order]
     grp_counts = jax.ops.segment_sum(valid.astype(jnp.int32), d,
                                      num_segments=n_dest + 1)
-    grp_start = _exclusive_cumsum(grp_counts)
+    grp_start = exclusive_prefix_sum(grp_counts)
     pos = jnp.arange(cap, dtype=jnp.int32) - grp_start[gd]
     req_ok = (gd < n_dest) & (pos < req_cap)
     # NB: .at[] wraps negative indices (NumPy semantics); the drop
@@ -93,8 +91,8 @@ def bucket_by_dest(r: RequestList, starts: jax.Array, data: jax.Array,
     # --- element-level routing ---------------------------------------
     # payload start of each request within its destination bucket:
     # prefix of lengths among same-dest requests placed before it.
-    gpre = jnp.cumsum(gl) - gl                      # global prefix, grouped
-    elem_grp_start = _exclusive_cumsum(
+    gpre = exclusive_prefix_sum(gl)                 # global prefix, grouped
+    elem_grp_start = exclusive_prefix_sum(
         jax.ops.segment_sum(jnp.where(valid, r.lengths, 0), d,
                             num_segments=n_dest + 1))
     dstart_grouped = gpre - elem_grp_start[gd]      # within-dest start
@@ -102,9 +100,7 @@ def bucket_by_dest(r: RequestList, starts: jax.Array, data: jax.Array,
 
     total = jnp.sum(jnp.where(valid, r.lengths, 0), dtype=jnp.int32)
     eidx = jnp.arange(in_dcap, dtype=jnp.int32)
-    req_of = jnp.repeat(jnp.arange(cap, dtype=jnp.int32),
-                        jnp.where(valid, r.lengths, 0),
-                        total_repeat_length=in_dcap)
+    req_of = segment_ids(jnp.where(valid, r.lengths, 0), in_dcap)
     e_valid = eidx < total
     e_dest = d[req_of]
     e_pos = req_dstart[req_of] + (eidx - starts[req_of])
@@ -112,7 +108,9 @@ def bucket_by_dest(r: RequestList, starts: jax.Array, data: jax.Array,
     e_scatter = jnp.where(e_ok, e_dest * data_cap + e_pos, n_dest * data_cap)
     out_data = jnp.zeros((n_dest * data_cap,), data.dtype)
     out_data = out_data.at[e_scatter].set(data, mode="drop")
-    dropped_elems = jnp.sum(e_valid & (e_dest < n_dest) & ~e_ok)
+    # payload past the end of ``data`` (total > in_dcap) is dropped too
+    dropped_elems = (jnp.sum(e_valid & (e_dest < n_dest) & ~e_ok)
+                     + jnp.maximum(total - in_dcap, 0))
 
     return Buckets(out_off.reshape(n_dest, req_cap),
                    out_len.reshape(n_dest, req_cap),
@@ -131,7 +129,7 @@ def flatten_buckets(offsets: jax.Array, lengths: jax.Array,
     nb, cap = b_off.shape
     dcap = data.shape[-1]
     # starts within each bucket, offset by the bucket's slab in flat data
-    per_bucket_starts = (jnp.cumsum(b_len, axis=-1) - b_len).astype(jnp.int32)
+    per_bucket_starts = exclusive_prefix_sum(b_len)
     slab = (jnp.arange(nb, dtype=jnp.int32) * dcap)[:, None]
     starts = (per_bucket_starts + slab).reshape(-1)
     # NOTE: padding is interspersed (per-bucket suffixes) — the prefix
@@ -152,9 +150,8 @@ def repack_sorted(r_sorted: RequestList, starts: jax.Array,
     """
     total = jnp.sum(r_sorted.lengths, dtype=jnp.int32)
     eidx = jnp.arange(out_cap, dtype=jnp.int32)
-    req_of = jnp.repeat(jnp.arange(r_sorted.capacity, dtype=jnp.int32),
-                        r_sorted.lengths, total_repeat_length=out_cap)
-    new_starts = (jnp.cumsum(r_sorted.lengths) - r_sorted.lengths).astype(jnp.int32)
+    req_of = segment_ids(r_sorted.lengths, out_cap)
+    new_starts = exclusive_prefix_sum(r_sorted.lengths)
     src = starts[req_of] + (eidx - new_starts[req_of])
     vals = data_flat[jnp.clip(src, 0, data_flat.shape[0] - 1)]
     return jnp.where(eidx < total, vals, jnp.zeros((), data_flat.dtype))

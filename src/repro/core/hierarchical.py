@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
 from repro.core import codec as codec_mod
 
 
@@ -44,7 +43,7 @@ def two_layer_psum(x: jax.Array, fast_axis: str, slow_axis: str) -> jax.Array:
     (1/q of the bytes) and exposes the slow hop for compression.
     """
     orig_shape = x.shape
-    q = axis_size(fast_axis)
+    q = lax.axis_size(fast_axis)
     flat, n = _pad_to(x.reshape(-1), q)
     shard = lax.psum_scatter(flat, fast_axis, scatter_dimension=0,
                              tiled=True)                   # intra: RS
@@ -89,7 +88,7 @@ def compressed_psum(x: jax.Array, residual: jax.Array, fast_axis: str,
     """
     ef = codec_mod.get_codec("ef-int8")
     orig_shape = x.shape
-    q = axis_size(fast_axis)
+    q = lax.axis_size(fast_axis)
     flat, n = _pad_to(x.reshape(-1), q)
     shard = lax.psum_scatter(flat, fast_axis, scatter_dimension=0,
                              tiled=True)
@@ -115,7 +114,7 @@ def two_layer_all_to_all(x: jax.Array, fast_axis: str, slow_axis: str) -> jax.Ar
     axes, but every slow-axis message is a q-chunk aggregate (fewer,
     larger slow-axis transfers — TAM's congestion fix for MoE dispatch).
     """
-    ns, nf = axis_size(slow_axis), axis_size(fast_axis)
+    ns, nf = lax.axis_size(slow_axis), lax.axis_size(fast_axis)
     assert x.shape[0] == ns * nf, "leading dim must be n_slow * n_fast"
     tail = x.shape[1:]
     # group by (dest pod, dest fast slot): grouped[t, u] -> rank (t, u)

@@ -130,7 +130,8 @@ from repro.core.exchange import (bucket_by_dest, flatten_buckets,
 # RoundScheduler folded into the plan IR (PR 3); re-exported here so
 # ``from repro.core.rounds import RoundScheduler`` keeps working.
 from repro.core.plan import RoundScheduler  # noqa: F401
-from repro.core.requests import PAD_OFFSET, RequestList, split_at_stripes
+from repro.core.requests import (PAD_OFFSET, RequestList, compact,
+                                 segment_ids, split_at_stripes)
 
 
 def _codec_hooks(slow_hop_codec: str | None, dtype, state_shape,
@@ -221,14 +222,14 @@ def _effective_depth(pipeline: bool, depth: int | None) -> int:
 
 def _compact_active(r: RequestList, starts: jax.Array, dest: jax.Array,
                     active: jax.Array):
-    """Move the active requests to the front, preserving offset order."""
-    off = jnp.where(active, r.offsets, PAD_OFFSET)
-    ln = jnp.where(active, r.lengths, 0)
-    order = jnp.argsort(jnp.where(active, 0, 1).astype(jnp.int32),
-                        stable=True)
-    return (RequestList(off[order], ln[order],
-                        jnp.sum(active, dtype=jnp.int32)),
-            starts[order], dest[order])
+    """Move the active requests to the front, preserving offset order
+    (the inactive tail holds padding requests, with start and dest 0)."""
+    off, ln, starts, dest = compact(active, r.offsets, r.lengths, starts,
+                                    dest)
+    count = jnp.sum(active, dtype=jnp.int32)
+    live = jnp.arange(active.shape[0], dtype=jnp.int32) < count
+    return (RequestList(jnp.where(live, off, PAD_OFFSET), ln, count),
+            starts, dest)
 
 
 def _lowest(dtype) -> jax.Array:
@@ -371,7 +372,7 @@ def exchange_rounds_write(sched: RoundScheduler, node_axis: str,
     fused = kernel_fusion == "fused_round"
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
     data_cap = data.shape[0]
-    split = split_at_stripes(r, cb, sched.max_spans(data_cap))
+    split = split_at_stripes(r, cb, sched.max_spans(data_cap), data_cap)
     s_starts = co.request_starts(split)
     to_slot, base0, unpermute = _placement_hooks(placement, n_dest, dl,
                                                  node_axis)
@@ -379,6 +380,10 @@ def exchange_rounds_write(sched: RoundScheduler, node_axis: str,
     window = sched.window_of(split.offsets)
     round_req_cap = min(split.capacity, cb)
     round_data_cap = min(data_cap, cb)
+    # a round's payload fills at most one cb window per destination:
+    # repacking only that much keeps a round's work O(window), not
+    # O(data_cap) (any excess is over a bucket's cap and counted dropped)
+    round_in_cap = min(data_cap, n_dest * round_data_cap)
     a2a = partial(lax.all_to_all, axis_name=node_axis, split_axis=0,
                   concat_axis=0, tiled=True)
     enc, dec, cstate0 = _codec_hooks(slow_hop_codec, data.dtype,
@@ -389,7 +394,7 @@ def exchange_rounds_write(sched: RoundScheduler, node_axis: str,
         active = split.valid_mask() & (window == t)
         act_r, act_starts, act_dest = _compact_active(split, s_starts,
                                                       dest, active)
-        act_data = repack_sorted(act_r, act_starts, data, data_cap)
+        act_data = repack_sorted(act_r, act_starts, data, round_in_cap)
         b = bucket_by_dest(act_r, co.request_starts(act_r), act_data,
                            act_dest, n_dest, round_req_cap, round_data_cap)
         wire, cst = enc(b.data, cst)
@@ -443,7 +448,7 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
     fused = kernel_fusion == "fused_round"
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
     data_cap = data.shape[0]
-    split = split_at_stripes(r, cb, sched.max_spans(data_cap))
+    split = split_at_stripes(r, cb, sched.max_spans(data_cap), data_cap)
     s_starts = co.request_starts(split)
     dest0 = (split.offsets // dl).astype(jnp.int32)
     window = sched.window_of(split.offsets)
@@ -460,8 +465,7 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
     # the codec wraps ONLY the slow-axis hop (stage 2): the intra-node
     # gather stays raw — exactly the paper's asymmetry (compress where
     # the fabric is slow), mirroring hierarchical.compressed_psum
-    from repro.compat import axis_size
-    lmem_size = axis_size(lmem_axis)
+    lmem_size = lax.axis_size(lmem_axis)
     enc, dec, cstate0 = _codec_hooks(
         slow_hop_codec, data.dtype,
         (n_dest, min(lmem_size * rdcap, cb)), fused=fused)
@@ -560,7 +564,6 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
     strategy only, never routing).
     """
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
-    cap = r.capacity
     if not placement_mod.is_identity(placement):
         perm = placement_mod.validate_placement(placement, n_dest)
         # slot perm[g] serves domain g: hand it the domain's shard
@@ -570,8 +573,7 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
     else:
         slot_of = None
     eidx = jnp.arange(data_cap, dtype=jnp.int32)
-    req_of = jnp.repeat(jnp.arange(cap, dtype=jnp.int32), r.lengths,
-                        total_repeat_length=data_cap)
+    req_of = segment_ids(r.lengths, data_cap)
     fpos = r.offsets[req_of] + (eidx - starts[req_of])
     live = eidx < jnp.sum(r.lengths, dtype=jnp.int32)
     fpos = jnp.where(live, fpos, 0)

@@ -35,7 +35,6 @@ import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core import coalesce as co
 from repro.core import rounds
 from repro.core.plan import IOPlan, compile_plan
@@ -68,7 +67,7 @@ def _write_shard_fn(plan: IOPlan, use_kernels: bool,
             slow_hop_codec=plan.slow_hop_codec,
             placement=plan.placement,
             kernel_fusion=plan.kernel_fusion)
-        lmem_size = axis_size(lmem)
+        lmem_size = lax.axis_size(lmem)
         all_axes = (node, lagg, lmem)
         stats = {
             "dropped_requests":
@@ -148,7 +147,7 @@ def make_spmd_executor(mesh: jax.sharding.Mesh, plan: IOPlan,
             f"axis {node!r} has size {mesh.shape[node]}")
     rank_spec = P((node, lagg, lmem))
     if plan.direction == "read":
-        return shard_map(
+        return jax.shard_map(
             partial(_read_shard_fn, plan), mesh=mesh, check_vma=False,
             in_specs=(rank_spec, rank_spec, rank_spec, P(node)),
             out_specs=rank_spec)
@@ -157,7 +156,7 @@ def make_spmd_executor(mesh: jax.sharding.Mesh, plan: IOPlan,
     if plan.method == "tam":
         stats_spec.update({"requests_before_coalesce": P(),
                            "requests_after_coalesce": P()})
-    return shard_map(
+    return jax.shard_map(
         partial(_write_shard_fn, plan, use_kernels), mesh=mesh,
         check_vma=False,
         in_specs=(rank_spec, rank_spec, rank_spec, rank_spec),

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.requests import PAD_OFFSET
+from repro.kernels import interpret_mode
 
 MAX_BLOCK = 32768
 
@@ -71,7 +72,7 @@ def _coalesce_block(off: jax.Array, ln: jax.Array):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def coalesce(offsets: jax.Array, lengths: jax.Array, *,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """Coalesce a batch of sorted request blocks.
 
     offsets/lengths: int32[b, n], offset-sorted with PAD_OFFSET padding
@@ -99,5 +100,5 @@ def coalesce(offsets: jax.Array, lengths: jax.Array, *,
         in_specs=[block, block],
         out_specs=[block, block, cnt_spec],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(offsets, lengths)

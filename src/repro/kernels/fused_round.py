@@ -43,8 +43,8 @@ Both kernels are selected by the planner's ``lower_kernels`` pass
 (``IOPlan.kernel_fusion == "fused_round"``) and consumed by
 ``core.rounds`` — byte-identity with the unfused jnp path under every
 placement x codec x depth is the acceptance contract (rounds_checks).
-Validated with interpret=True on CPU per the build rules; blocks obey
-the TPU constraints (power-of-two request blocks, aligned output
+Off the TPU they run in interpret mode (``kernels.interpret_mode``); blocks
+aim at the TPU constraints (power-of-two request blocks, aligned output
 tiles, >= 2D iota via broadcasted_iota).
 """
 from __future__ import annotations
@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.kernels.pack import MAX_REQ_BLOCK, TILE, _searchsorted_right
 from repro.kernels.sort import _bitonic_sort_body
 
@@ -63,7 +64,7 @@ from repro.kernels.sort import _bitonic_sort_body
 @functools.partial(jax.jit, static_argnames=("out_len", "interpret"))
 def fused_sort_pack(offsets: jax.Array, lengths: jax.Array,
                     starts: jax.Array, data: jax.Array, base,
-                    out_len: int, *, interpret: bool = True):
+                    out_len: int, *, interpret: bool | None = None):
     """Sort + dual-pack one drain window in a single kernel.
 
     offsets/lengths/starts: int32[cap] request metadata, cap a power of
@@ -120,13 +121,13 @@ def fused_sort_pack(offsets: jax.Array, lengths: jax.Array,
         out_specs=[tspec, tspec],
         out_shape=[jax.ShapeDtypeStruct((out_len,), data.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((cap,), jnp.int32)] * 3,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(offsets, lengths, starts, data, base)
     return win, mask
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def zero_skip_encode(data: jax.Array, *, interpret: bool = True):
+def zero_skip_encode(data: jax.Array, *, interpret: bool | None = None):
     """Zero-skipping compaction of payload rows — the rle codec's SPMD
     wire form, fused into one VMEM block per row.
 
@@ -164,13 +165,13 @@ def zero_skip_encode(data: jax.Array, *, interpret: bool = True):
         out_specs=[block, block],
         out_shape=[jax.ShapeDtypeStruct((rows, n), data.dtype),
                    jax.ShapeDtypeStruct((rows, n), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(data)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def zero_skip_decode(vals: jax.Array, pos: jax.Array, *,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Expand zero-skip compacted rows back into dense windows — the
     rle codec's decode scatter, fused into one VMEM block per row.
 
@@ -199,5 +200,5 @@ def zero_skip_decode(vals: jax.Array, pos: jax.Array, *,
         in_specs=[block, block],
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct((rows, n), vals.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(vals, pos)

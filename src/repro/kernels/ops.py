@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles: padding to power-of-two block sizes, RequestList integration,
-large-list chunking (chunk-sort + merge), and interpret-mode dispatch
-(interpret=True on CPU — per the build rules kernels target TPU but are
-validated on the CPU interpreter).
+and large-list chunking (chunk-sort + merge). ``interpret=None`` passes
+through to the kernels, which resolve it from the backend
+(``kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -12,10 +12,6 @@ import jax.numpy as jnp
 
 from repro.core.requests import PAD_OFFSET, RequestList
 from repro.kernels import coalesce_kernel, pack as pack_mod, sort as sort_mod
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _next_pow2(n: int) -> int:
@@ -39,7 +35,6 @@ def sort_requests_with(r: RequestList, starts: jax.Array,
     is cheap relative to the in-block network; on TPU it would be a
     bitonic inter-block merge, see kernels/sort.py docstring).
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
     cap = r.capacity
     n = _next_pow2(cap)
     if n <= sort_mod.MAX_BLOCK:
@@ -63,7 +58,6 @@ def sort_requests_with(r: RequestList, starts: jax.Array,
 
 def coalesce(r: RequestList, interpret: bool | None = None) -> RequestList:
     """Kernel-backed equivalent of ``coalesce.coalesce_sorted``."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     cap = r.capacity
     n = min(_next_pow2(cap), max(_next_pow2(cap), 8))
     off = _pad_block(r.offsets[None], n, PAD_OFFSET)
@@ -80,7 +74,6 @@ def pack(r: RequestList, starts: jax.Array, data: jax.Array, base,
     gather formulation exploits). out_len is padded to the tile size
     internally; the caller receives exactly [out_len].
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
     cap = _next_pow2(r.capacity)
     off = _pad_block(r.offsets, cap, PAD_OFFSET)
     ln = _pad_block(r.lengths, cap, 0)
@@ -103,7 +96,6 @@ def fused_drain_pack(r: RequestList, starts: jax.Array, data: jax.Array,
     """
     from repro.kernels import fused_round
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
     cap = _next_pow2(r.capacity)
     off = _pad_block(r.offsets, cap, PAD_OFFSET)
     ln = _pad_block(r.lengths, cap, 0)
@@ -122,7 +114,6 @@ def rle_zero_skip_encode(data: jax.Array, interpret: bool | None = None):
     the codec's exact wire layout (pos == -1 in the padding)."""
     from repro.kernels import fused_round
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
     lead, cap = data.shape[:-1], data.shape[-1]
     n = _next_pow2(cap)
     rows = data.reshape(-1, cap)
@@ -139,7 +130,6 @@ def rle_zero_skip_decode(parts, interpret: bool | None = None):
     sentinel), scatters in VMEM, slices back to the window shape."""
     from repro.kernels import fused_round
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
     vals, pos = parts
     lead, cap = vals.shape[:-1], vals.shape[-1]
     n = _next_pow2(cap)
@@ -147,34 +137,3 @@ def rle_zero_skip_decode(parts, interpret: bool | None = None):
     p = _pad_block(pos.reshape(-1, cap), n, -1)
     out = fused_round.zero_skip_decode(v, p, interpret=interpret)
     return out[:, :cap].reshape(*lead, cap)
-
-
-def fused_attention(q, k, v, *, causal: bool = True,
-                    window: int | None = None,
-                    logit_cap: float | None = None, q_offset: int = 0,
-                    interpret: bool | None = None):
-    """Padding wrapper over kernels.flash.flash_attention_fused:
-    accepts arbitrary Sq/Skv, pads to block sizes, bounds real keys with
-    kv_len (padded keys never enter the softmax), slices back.
-    """
-    from repro.kernels import flash
-
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    b, sq, hq, hd = q.shape
-    skv = k.shape[1]
-    bq = min(flash.BLOCK_Q, max(64, 1 << (sq - 1).bit_length()))
-    bkv = min(flash.BLOCK_KV, max(64, 1 << (skv - 1).bit_length()))
-    pq = -(-sq // bq) * bq - sq
-    pk = -(-skv // bkv) * bkv - skv
-    window_eff = window
-    qp = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    # padded keys sit at positions >= skv: mask them with causality when
-    # causal (q_offset + sq <= skv pad positions) — for causal callers
-    # with q_offset+sq == skv this is automatic.
-    out = flash.flash_attention_fused(
-        qp, kp, vp, causal=causal, window=window_eff,
-        logit_cap=logit_cap, q_offset=q_offset, kv_len=skv,
-        interpret=interpret, block_q=bq, block_kv=bkv)
-    return out[:, :sq]

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 MAX_REQ_BLOCK = 32768
 TILE = 4096
 
@@ -54,7 +56,7 @@ def _pack_tile(off, ln, starts, data, base, tile_start, tile):
 
 @functools.partial(jax.jit, static_argnames=("out_len", "interpret"))
 def pack(offsets: jax.Array, lengths: jax.Array, starts: jax.Array,
-         data: jax.Array, base, out_len: int, *, interpret: bool = True):
+         data: jax.Array, base, out_len: int, *, interpret: bool | None = None):
     """Gather-style pack of payloads into a dense [out_len] buffer.
 
     offsets/lengths/starts: int32[cap] — offset-SORTED, non-overlapping
@@ -86,5 +88,5 @@ def pack(offsets: jax.Array, lengths: jax.Array, starts: jax.Array,
         in_specs=[meta, meta, meta, dspec, bspec],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((out_len,), data.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(offsets, lengths, starts, data, base)

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 MAX_BLOCK = 32768  # pairs per VMEM block (power of two)
 
 
@@ -70,7 +72,7 @@ def _sort_kernel(off_ref, len_ref, carry_ref, off_out, len_out, carry_out):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bitonic_sort(offsets: jax.Array, lengths: jax.Array, carry: jax.Array,
-                 *, interpret: bool = True):
+                 *, interpret: bool | None = None):
     """Sort one batch of request blocks by offset.
 
     offsets/lengths/carry: int32[b, n] with n a power of two <= MAX_BLOCK.
@@ -98,5 +100,5 @@ def bitonic_sort(offsets: jax.Array, lengths: jax.Array, carry: jax.Array,
         in_specs=[block, block, block],
         out_specs=[flat, flat, flat],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(offsets, lengths, carry)

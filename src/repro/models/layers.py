@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import shard_map
 from repro.models.config import ModelConfig
 from repro.models.sharding import ShardingPlan
 
@@ -219,7 +218,7 @@ def decode_attention_sharded(q, k_cache, v_cache, *, cache_pos,
         out = acc / jnp.maximum(l[..., None], 1e-30)
         return out.reshape(b, 1, hq * hd).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, check_vma=False,
         in_specs=(P(dp, None, None, None), P(dp, tp, None, None),
                   P(dp, tp, None, None), P()),

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.config import ModelConfig
 from repro.models.sharding import ShardingPlan
 
@@ -159,7 +158,7 @@ def moe_sharded(p: dict, x: jax.Array, cfg: ModelConfig,
                 aux = lax.pmean(aux, dp_axes)
             return y.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fn, mesh=mesh, check_vma=False,
         in_specs=(x_spec, r_spec, w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_apply(stage_fn, mesh, *, axis: str = "pipe",
@@ -81,7 +80,7 @@ def pipeline_apply(stage_fn, mesh, *, axis: str = "pipe",
             outs.shape[0] // S, 0)
         return mine
 
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh, check_vma=False,
         in_specs=(P(axis), P(axis)),
         out_specs=P(axis))

@@ -21,9 +21,8 @@ import warnings
 from dataclasses import dataclass
 from pathlib import Path
 
-import jax
-
 from repro.core.faults import partial_marker
+from repro.launch.mesh import make_mesh
 
 
 @dataclass(frozen=True)
@@ -36,7 +35,7 @@ class ElasticPlan:
     unused_devices: int = 0
 
     def make_mesh(self):
-        return jax.make_mesh(self.mesh_shape, self.axis_names)
+        return make_mesh(self.mesh_shape, self.axis_names)
 
 
 def plan_remesh(total_devices: int, model_parallel: int,

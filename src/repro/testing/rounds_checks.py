@@ -238,8 +238,9 @@ def main():
     from repro.core.twophase import (make_twophase_read,
                                      make_twophase_write, write_reference)
     from repro.checkpoint.host_io import HostCollectiveIO
+    from repro.launch.mesh import make_io_mesh
 
-    mesh = jax.make_mesh((2, 2, 2), ("node", "lagg", "lmem"))
+    mesh = make_io_mesh(2, 2, 2)
     layout = contiguous_layout(FILE_LEN, 2)
     base = IOConfig(req_cap=32, data_cap=DATA_CAP, coalesce_cap=32)
 

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 
 FAILURES = []
 
@@ -33,7 +33,7 @@ def main():
                                          two_layer_all_to_all,
                                          two_layer_psum)
 
-    mesh = jax.make_mesh((2, 2, 2), ("node", "lagg", "lmem"))
+    mesh = make_mesh((2, 2, 2), ("node", "lagg", "lmem"))
     P_ranks, REQ_CAP, DATA_CAP, FILE_LEN = 8, 8, 64, 256
     layout = contiguous_layout(FILE_LEN, 2)
     rng = np.random.default_rng(0)
@@ -91,16 +91,16 @@ def main():
           <= int(s["requests_before_coalesce"]))
 
     # ---- hierarchical collectives ------------------------------------
-    mesh2 = jax.make_mesh((2, 4), ("pod", "ici"))
+    mesh2 = make_mesh((2, 4), ("pod", "ici"))
     x = jnp.asarray(rng.normal(size=(8, 33)).astype(np.float32))
-    r2 = jax.jit(shard_map(
+    r2 = jax.jit(jax.shard_map(
         lambda xs: two_layer_psum(xs.reshape(33), "ici", "pod"),
         mesh=mesh2, in_specs=P(("pod", "ici")), out_specs=P(),
         check_vma=False))(x)
     check("two_layer_psum",
           np.allclose(np.asarray(r2), np.asarray(x.sum(0)), atol=1e-4))
 
-    outc, nres = jax.jit(shard_map(
+    outc, nres = jax.jit(jax.shard_map(
         lambda xs, res: compressed_psum(xs.reshape(33), res.reshape(33),
                                         "ici", "pod"),
         mesh=mesh2, in_specs=(P(("pod", "ici")), P(("pod", "ici"))),
@@ -113,7 +113,7 @@ def main():
           float(jnp.abs(nres).sum()) > 0)
 
     xa = jnp.arange(8 * 8 * 5, dtype=jnp.int32).reshape(8, 8 * 5)
-    ra = jax.jit(shard_map(
+    ra = jax.jit(jax.shard_map(
         lambda xs: two_layer_all_to_all(xs.reshape(8, 5), "ici", "pod"),
         mesh=mesh2, in_specs=P(("pod", "ici")), out_specs=P(("pod", "ici")),
         check_vma=False))(xa)
@@ -130,7 +130,7 @@ def main():
     from repro.models.config import reduced
     from repro.models.sharding import ShardingPlan, unsharded
 
-    mesh3 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh3 = make_mesh((2, 4), ("data", "model"))
     cfg_m = reduced(configs.get("llama4_maverick"))
     cfg_m = dreplace(cfg_m, moe=dreplace(cfg_m.moe, capacity_factor=4.0),
                      d_model=32, vocab=256)

@@ -10,6 +10,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from repro.launch.hlo_analysis import HloCostModel
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 # 1. while-loop flops multiplied by trip count (XLA counts body once)
@@ -31,7 +32,7 @@ t2 = HloCostModel(c2.as_text()).total()
 assert abs(t2.flops - 5 * expected) / (5 * expected) < 0.01
 
 # 3. collective wire bytes: psum of 4KB over a 4-ring = 2*(3/4)*4KB
-mesh = jax.make_mesh((4,), ("d",))
+mesh = make_mesh((4,), ("d",))
 f = jax.jit(lambda x: x.sum(0, keepdims=True),
             in_shardings=NamedSharding(mesh, P("d", None)),
             out_shardings=NamedSharding(mesh, P(None, None)))

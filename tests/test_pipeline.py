@@ -8,9 +8,10 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.models.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = make_mesh((4,), ("pipe",))
 S, M, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 W = jax.random.normal(key, (S, d, d)) * 0.3

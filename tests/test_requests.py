@@ -7,8 +7,9 @@ from _hyp_compat import given, settings, st
 from repro.core import coalesce as co
 from repro.core.domains import (FileLayout, contiguous_layout, from_domain_local,
                                 owner_of, to_domain_local)
-from repro.core.requests import (PAD_OFFSET, RequestList, empty_requests,
-                                 make_requests, split_at_stripes)
+from repro.core.requests import (PAD_OFFSET, SCAN_BLOCK, RequestList,
+                                 compact, empty_requests, make_requests,
+                                 prefix_sum, segment_ids, split_at_stripes)
 
 
 def random_requests(rng, n, max_gap=20, max_len=8):
@@ -37,6 +38,64 @@ def test_split_at_stripes():
     assert all(o // 16 == (o + l - 1) // 16 for o, l in zip(offs, lens))
     # total length preserved
     assert lens.sum() == 8 + 25 + 2
+
+
+@pytest.mark.parametrize("shape", [(1,), (SCAN_BLOCK,), (SCAN_BLOCK + 1,),
+                                   (3 * SCAN_BLOCK**2 + 5,), (4, 2500)])
+def test_prefix_sum_matches_cumsum(shape):
+    x = np.random.default_rng(len(shape)).integers(-9, 9, size=shape)
+    got = np.asarray(prefix_sum(jnp.asarray(x, jnp.int32)))
+    assert np.array_equal(got, np.cumsum(x, axis=-1))
+
+
+@pytest.mark.parametrize("total", [0, 40, 400, 3000])
+def test_segment_ids_match_repeat(total):
+    lengths = np.random.default_rng(total).integers(0, 12, size=64)
+    lengths[::7] = 0                       # zero-length requests anywhere
+    if total == 0:
+        total = int(lengths.sum())         # exactly full
+    got = segment_ids(jnp.asarray(lengths, jnp.int32), total)
+    want = jnp.repeat(jnp.arange(64, dtype=jnp.int32),
+                      jnp.asarray(lengths, jnp.int32),
+                      total_repeat_length=total)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n", [5, 1500])
+def test_compact_matches_stable_argsort(n):
+    rng = np.random.default_rng(n)
+    keep = rng.random(n) < 0.4
+    x = rng.integers(-100, 100, size=n).astype(np.int32)
+    (got,) = compact(jnp.asarray(keep), jnp.asarray(x))
+    want = x[np.argsort(~keep, kind="stable")]
+    k = int(keep.sum())
+    assert np.array_equal(np.asarray(got)[:k], want[:k])
+    assert not np.asarray(got)[k:].any()
+
+
+@pytest.mark.parametrize("stripe,max_spans,data_cap", [
+    (16, 3, None), (16, 2, None), (5, 9, None), (5, 9, 75), (1, 80, 75)])
+def test_split_at_stripes_matches_loop(stripe, max_spans, data_cap):
+    offs = [0, 10, 30, 47, 90]
+    lens = [8, 25, 2, 40, 0]
+    r = make_requests(offs, lens, capacity=7)
+    want = []
+    for o, ln in zip(offs, lens):
+        spans = []
+        while ln > 0 and len(spans) < max_spans:
+            take = min(ln, stripe - o % stripe)
+            spans.append((o, take))
+            o, ln = o + take, ln - take
+        want += spans
+    s = split_at_stripes(r, stripe, max_spans, data_cap)
+    n = int(s.count)
+    assert s.capacity == (7 * max_spans if data_cap is None else
+                          7 + min(7 * (max_spans - 1), 7 + data_cap // stripe))
+    assert n == len(want)
+    assert list(zip(np.asarray(s.offsets[:n]).tolist(),
+                    np.asarray(s.lengths[:n]).tolist())) == want
+    assert (np.asarray(s.offsets[n:]) == PAD_OFFSET).all()
+    assert not np.asarray(s.lengths[n:]).any()
 
 
 def test_coalesce_adjacent():
