@@ -1,0 +1,10 @@
+"""Share of the wall time of the read calls (host clock) in which no op
+ran on the device inside the read programs' executions (profiler trace),
+averaged over the chips (%)."""
+
+
+def read(ctx):
+    busy = ctx.device_busy_s("read")
+    if busy is None:
+        return None
+    return 100.0 * (1.0 - busy / ctx.wall_s("read"))
