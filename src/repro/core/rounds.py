@@ -102,6 +102,25 @@ the masked max-combine resolves each element deterministically to the
 maximum written value, and capacity overflow is reported through the
 ``dropped_requests`` / ``dropped_elems`` stats, never silent.
 
+Stage scopes and slow-hop counters
+----------------------------------
+Every stage of the round loop runs under a flat ``jax.named_scope``,
+so each compiled op's ``op_name`` metadata names the stage it belongs
+to (its innermost ``io.`` component): ``io.split`` (the once-per-write
+split, starts, destinations and windows), ``io.select`` (the per-round
+compaction of the window's requests and its payload repack),
+``io.stage1`` (TAM's intra-node gather, sort, repack, coalesce and
+re-split), ``io.exchange`` (bucketing, the codec's encode and the
+slow-axis ``all_to_all``), ``io.drain`` (decode, flatten, sort and
+pack of a received window), ``io.merge`` (the masked ``pmax`` merge
+and the accumulate at ``t * cb``); the read has ``io.index`` (the
+once-per-read element index), ``io.fetch`` (the window broadcast) and
+``io.scatter`` (placing the window's elements). The scopes are
+metadata only: they add no op. Both writes also count, per round, the
+requested payload elements the slow-axis ``all_to_all`` carries
+(``slow_hop_live_elems``) and the elements it moves, padded buckets of
+every wire part included (``slow_hop_shipped_elems``).
+
 Cost-model coupling
 -------------------
 The executed round count is ``RoundScheduler.n_rounds`` ==
@@ -220,6 +239,23 @@ def _effective_depth(pipeline: bool, depth: int | None) -> int:
     return 2 if pipeline else 1
 
 
+# Stage scopes (module docstring): flat, so an op's innermost ``io.``
+# component is its stage.
+SPLIT, SELECT, STAGE1, EXCHANGE, DRAIN, MERGE = (
+    "io.split", "io.select", "io.stage1", "io.exchange", "io.drain",
+    "io.merge")
+INDEX, FETCH, SCATTER = "io.index", "io.fetch", "io.scatter"
+
+
+def _slow_hop_counts(r: RequestList, b, wire) -> tuple:
+    """(live, shipped) elements of one rank's slow-axis exchange:
+    payload of ``r`` placed in the buckets ``b``, and every element of
+    the wire parts the ``all_to_all`` moves, padding included."""
+    placed = jnp.sum(jnp.where(r.valid_mask(), r.lengths, 0),
+                     dtype=jnp.int32) - b.dropped_elems
+    return placed, jnp.int32(sum(p.size for p in wire))
+
+
 def _compact_active(r: RequestList, starts: jax.Array, dest: jax.Array,
                     active: jax.Array):
     """Move the active requests to the front, preserving offset order
@@ -253,24 +289,28 @@ def _make_drain(base0, cb: int, merge_axes: tuple[str, ...], dtype,
     low = _lowest(dtype)
 
     def drain(t, buf, rx):
-        data = rx[3] if decode is None else decode(rx[3:]).astype(dtype)
-        merged, starts_m, data_flat = flatten_buckets(rx[0], rx[1],
-                                                      rx[2], data)
-        base = base0 + t * cb
-        if fused:
-            from repro.kernels import ops as kops
-            win, mask = kops.fused_drain_pack(merged, starts_m,
-                                              data_flat, base, cb)
-        else:
-            sorted_r, starts_s = sort_with(merged, starts_m)
-            win = co.pack_data(sorted_r, starts_s, data_flat, cb,
-                               base=base)
-            mask = co.pack_data(sorted_r, starts_s,
-                                jnp.ones_like(data_flat), cb, base=base)
-        comb = lax.pmax(jnp.where(mask != 0, win, low), merge_axes)
-        anyw = lax.pmax(mask, merge_axes)
-        final = jnp.where(anyw != 0, comb, jnp.zeros((), dtype))
-        buf = lax.dynamic_update_slice(buf, final, (t * cb,))
+        with jax.named_scope(DRAIN):
+            data = (rx[3] if decode is None
+                    else decode(rx[3:]).astype(dtype))
+            merged, starts_m, data_flat = flatten_buckets(rx[0], rx[1],
+                                                          rx[2], data)
+            base = base0 + t * cb
+            if fused:
+                from repro.kernels import ops as kops
+                win, mask = kops.fused_drain_pack(merged, starts_m,
+                                                  data_flat, base, cb)
+            else:
+                sorted_r, starts_s = sort_with(merged, starts_m)
+                win = co.pack_data(sorted_r, starts_s, data_flat, cb,
+                                   base=base)
+                mask = co.pack_data(sorted_r, starts_s,
+                                    jnp.ones_like(data_flat), cb,
+                                    base=base)
+        with jax.named_scope(MERGE):
+            comb = lax.pmax(jnp.where(mask != 0, win, low), merge_axes)
+            anyw = lax.pmax(mask, merge_axes)
+            final = jnp.where(anyw != 0, comb, jnp.zeros((), dtype))
+            buf = lax.dynamic_update_slice(buf, final, (t * cb,))
         return buf, (merged.count,)
 
     return drain
@@ -363,7 +403,8 @@ def exchange_rounds_write(sched: RoundScheduler, node_axis: str,
     the output is byte-identical for every placement. Returns
     (domain shard [domain_len], stats dict); ``requests_at_ga`` is
     already summed over ``merge_axes`` (replicated at the node) and
-    reported in DOMAIN order whatever the placement.
+    reported in DOMAIN order whatever the placement; the
+    ``slow_hop_*_elems`` counts are this rank's, summed over rounds.
     ``kernel_fusion="fused_round"`` (``IOPlan.kernel_fusion``, set by
     the planner's ``lower_kernels`` pass) drains each window with the
     single fused Pallas kernel and, when the codec is rle, encodes the
@@ -372,12 +413,14 @@ def exchange_rounds_write(sched: RoundScheduler, node_axis: str,
     fused = kernel_fusion == "fused_round"
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
     data_cap = data.shape[0]
-    split = split_at_stripes(r, cb, sched.max_spans(data_cap), data_cap)
-    s_starts = co.request_starts(split)
-    to_slot, base0, unpermute = _placement_hooks(placement, n_dest, dl,
-                                                 node_axis)
-    dest = to_slot((split.offsets // dl).astype(jnp.int32))
-    window = sched.window_of(split.offsets)
+    with jax.named_scope(SPLIT):
+        split = split_at_stripes(r, cb, sched.max_spans(data_cap),
+                                 data_cap)
+        s_starts = co.request_starts(split)
+        to_slot, base0, unpermute = _placement_hooks(placement, n_dest,
+                                                     dl, node_axis)
+        dest = to_slot((split.offsets // dl).astype(jnp.int32))
+        window = sched.window_of(split.offsets)
     round_req_cap = min(split.capacity, cb)
     round_data_cap = min(data_cap, cb)
     # a round's payload fills at most one cb window per destination:
@@ -391,25 +434,31 @@ def exchange_rounds_write(sched: RoundScheduler, node_axis: str,
                                      fused=fused)
 
     def exchange(t, cst):
-        active = split.valid_mask() & (window == t)
-        act_r, act_starts, act_dest = _compact_active(split, s_starts,
-                                                      dest, active)
-        act_data = repack_sorted(act_r, act_starts, data, round_in_cap)
-        b = bucket_by_dest(act_r, co.request_starts(act_r), act_data,
-                           act_dest, n_dest, round_req_cap, round_data_cap)
-        wire, cst = enc(b.data, cst)
-        rx = ((a2a(b.offsets), a2a(b.lengths), a2a(b.counts))
-              + tuple(a2a(p) for p in wire))
-        return rx, (b.dropped_requests, b.dropped_elems), cst
+        with jax.named_scope(SELECT):
+            active = split.valid_mask() & (window == t)
+            act_r, act_starts, act_dest = _compact_active(split, s_starts,
+                                                          dest, active)
+            act_data = repack_sorted(act_r, act_starts, data, round_in_cap)
+        with jax.named_scope(EXCHANGE):
+            b = bucket_by_dest(act_r, co.request_starts(act_r), act_data,
+                               act_dest, n_dest, round_req_cap,
+                               round_data_cap)
+            wire, cst = enc(b.data, cst)
+            rx = ((a2a(b.offsets), a2a(b.lengths), a2a(b.counts))
+                  + tuple(a2a(p) for p in wire))
+            live, shipped = _slow_hop_counts(act_r, b, wire)
+        return rx, (b.dropped_requests, b.dropped_elems, live, shipped), cst
 
     drain = _make_drain(base0, cb, merge_axes, data.dtype, decode=dec,
                         fused=fused)
-    buf, (drop_r, drop_e), (reqs_rx,) = _run_rounds(
-        sched.n_rounds, dl, data.dtype, exchange, drain, 2, 1,
+    buf, (drop_r, drop_e, live, shipped), (reqs_rx,) = _run_rounds(
+        sched.n_rounds, dl, data.dtype, exchange, drain, 4, 1,
         _effective_depth(pipeline, depth), codec_state=cstate0)
     return unpermute(buf), {
         "dropped_requests": drop_r,
         "dropped_elems": drop_e,
+        "slow_hop_live_elems": live,
+        "slow_hop_shipped_elems": shipped,
         "requests_at_ga": unpermute(lax.psum(reqs_rx, merge_axes)),
     }
 
@@ -440,7 +489,9 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
     Returns (domain shard, stats). ``*_rank`` drop stats are per-rank
     (pre-gather — psum over all axes); ``*_agg`` drops and the
     before/after coalesce counts are replicated across ``lmem_axis``
-    (post-gather — divide the psum by the lmem size).
+    (post-gather — divide the psum by the lmem size), and so is
+    ``slow_hop_live_elems``; ``slow_hop_shipped_elems`` counts what this
+    rank's own ``all_to_all`` moves, copies included.
     ``kernel_fusion="fused_round"`` fuses the global-aggregator drain
     (and the rle wire encode) exactly as in
     :func:`exchange_rounds_write`.
@@ -448,16 +499,19 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
     fused = kernel_fusion == "fused_round"
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
     data_cap = data.shape[0]
-    split = split_at_stripes(r, cb, sched.max_spans(data_cap), data_cap)
-    s_starts = co.request_starts(split)
-    dest0 = (split.offsets // dl).astype(jnp.int32)
-    window = sched.window_of(split.offsets)
+    with jax.named_scope(SPLIT):
+        split = split_at_stripes(r, cb, sched.max_spans(data_cap),
+                                 data_cap)
+        s_starts = co.request_starts(split)
+        dest0 = (split.offsets // dl).astype(jnp.int32)
+        window = sched.window_of(split.offsets)
     rcap = min(split.capacity, cb)       # stage-1 requests/rank/round
     rdcap = min(data_cap, cb)            # stage-1 payload/rank/round
     # placement routes only the SLOW hop (stage 2): the intra-node
     # gather is placement-blind, mirroring the codec's asymmetry
-    to_slot, base0, unpermute = _placement_hooks(placement, n_dest, dl,
-                                                 node_axis)
+    with jax.named_scope(SPLIT):
+        to_slot, base0, unpermute = _placement_hooks(placement, n_dest,
+                                                     dl, node_axis)
     a2a = partial(lax.all_to_all, axis_name=node_axis, split_axis=0,
                   concat_axis=0, tiled=True)
     g = partial(lax.all_gather, axis_name=lmem_axis, axis=0, tiled=False)
@@ -471,61 +525,69 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
         (n_dest, min(lmem_size * rdcap, cb)), fused=fused)
 
     def exchange(t, cst):
-        # ---- stage 1: window-bounded intra-node aggregation ---------
-        active = split.valid_mask() & (window == t)
-        act_r, act_starts, _ = _compact_active(split, s_starts, dest0,
-                                               active)
-        drop_rank_r = jnp.maximum(act_r.count - rcap, 0)
-        drop_rank_e = jnp.sum(jnp.where(idx >= rcap, act_r.lengths, 0),
-                              dtype=jnp.int32)
-        win_r = RequestList(act_r.offsets[:rcap], act_r.lengths[:rcap],
-                            jnp.minimum(act_r.count, rcap))
-        drop_rank_e = drop_rank_e + jnp.maximum(
-            jnp.sum(win_r.lengths, dtype=jnp.int32) - rdcap, 0)
-        win_data = repack_sorted(win_r, act_starts[:rcap], data, rdcap)
-        all_off, all_len, all_cnt, all_data = (
-            g(win_r.offsets), g(win_r.lengths), g(win_r.count),
-            g(win_data))
-        m = all_off.shape[0]
-        merged, starts_m, data_flat = flatten_buckets(all_off, all_len,
-                                                      all_cnt, all_data)
-        if use_kernels:
-            from repro.kernels import ops as kops
-            sorted_r, starts_s = kops.sort_requests_with(merged, starts_m)
-            packed = repack_sorted(sorted_r, starts_s, data_flat, m * rdcap)
-            coal = kops.coalesce(sorted_r)
-        else:
-            sorted_r, starts_s = sort_with(merged, starts_m)
-            packed = repack_sorted(sorted_r, starts_s, data_flat, m * rdcap)
-            coal = co.coalesce_sorted(sorted_r)
-        ccap = min(coalesce_cap or coal.capacity, coal.capacity)
-        drop_agg_r = jnp.maximum(coal.count - ccap, 0)
-        agg = RequestList(coal.offsets[:ccap], coal.lengths[:ccap],
-                          jnp.minimum(coal.count, ccap))
-        # a coalesced run can escape its window only when cb == dl (the
-        # last window of domain d touches window 0 of domain d+1, both
-        # live in the single round) — re-split at the domain boundary so
-        # each forwarded request has exactly one owner
-        agg = split_at_stripes(agg, dl, m * rdcap // dl + 2)
-        # ---- stage 2: slow-axis exchange of the coalesced window ----
-        dest = to_slot((agg.offsets // dl).astype(jnp.int32))
-        b = bucket_by_dest(agg, co.request_starts(agg), packed, dest,
-                           n_dest, min(agg.capacity, cb),
-                           min(m * rdcap, cb))
-        wire, cst = enc(b.data, cst)
-        rx = ((a2a(b.offsets), a2a(b.lengths), a2a(b.counts))
-              + tuple(a2a(p) for p in wire))
+        with jax.named_scope(SELECT):
+            active = split.valid_mask() & (window == t)
+            act_r, act_starts, _ = _compact_active(split, s_starts, dest0,
+                                                   active)
+            drop_rank_r = jnp.maximum(act_r.count - rcap, 0)
+            drop_rank_e = jnp.sum(jnp.where(idx >= rcap, act_r.lengths, 0),
+                                  dtype=jnp.int32)
+            win_r = RequestList(act_r.offsets[:rcap], act_r.lengths[:rcap],
+                                jnp.minimum(act_r.count, rcap))
+            drop_rank_e = drop_rank_e + jnp.maximum(
+                jnp.sum(win_r.lengths, dtype=jnp.int32) - rdcap, 0)
+            win_data = repack_sorted(win_r, act_starts[:rcap], data, rdcap)
+        # ---- stage 1: window-bounded intra-node aggregation -------------
+        with jax.named_scope(STAGE1):
+            all_off, all_len, all_cnt, all_data = (
+                g(win_r.offsets), g(win_r.lengths), g(win_r.count),
+                g(win_data))
+            m = all_off.shape[0]
+            merged, starts_m, data_flat = flatten_buckets(
+                all_off, all_len, all_cnt, all_data)
+            if use_kernels:
+                from repro.kernels import ops as kops
+                sorted_r, starts_s = kops.sort_requests_with(merged,
+                                                             starts_m)
+                packed = repack_sorted(sorted_r, starts_s, data_flat,
+                                       m * rdcap)
+                coal = kops.coalesce(sorted_r)
+            else:
+                sorted_r, starts_s = sort_with(merged, starts_m)
+                packed = repack_sorted(sorted_r, starts_s, data_flat,
+                                       m * rdcap)
+                coal = co.coalesce_sorted(sorted_r)
+            ccap = min(coalesce_cap or coal.capacity, coal.capacity)
+            drop_agg_r = jnp.maximum(coal.count - ccap, 0)
+            agg = RequestList(coal.offsets[:ccap], coal.lengths[:ccap],
+                              jnp.minimum(coal.count, ccap))
+            # a coalesced run can escape its window only when cb == dl
+            # (the last window of domain d touches window 0 of domain
+            # d+1, both live in the single round) — re-split at the
+            # domain boundary so each forwarded request has exactly one
+            # owner
+            agg = split_at_stripes(agg, dl, m * rdcap // dl + 2)
+        # ---- stage 2: slow-axis exchange of the coalesced window --------
+        with jax.named_scope(EXCHANGE):
+            dest = to_slot((agg.offsets // dl).astype(jnp.int32))
+            b = bucket_by_dest(agg, co.request_starts(agg), packed, dest,
+                               n_dest, min(agg.capacity, cb),
+                               min(m * rdcap, cb))
+            wire, cst = enc(b.data, cst)
+            rx = ((a2a(b.offsets), a2a(b.lengths), a2a(b.counts))
+                  + tuple(a2a(p) for p in wire))
+            live, shipped = _slow_hop_counts(agg, b, wire)
         return rx, (drop_rank_r, drop_rank_e,
                     b.dropped_requests + drop_agg_r, b.dropped_elems,
-                    merged.count, agg.count), cst
+                    merged.count, agg.count, live, shipped), cst
 
     drain = _make_drain(base0, cb, (lagg_axis,), data.dtype, decode=dec,
                         fused=fused)
     buf, ex_acc, dr_acc = _run_rounds(
-        sched.n_rounds, dl, data.dtype, exchange, drain, 6, 1,
+        sched.n_rounds, dl, data.dtype, exchange, drain, 8, 1,
         _effective_depth(pipeline, depth), codec_state=cstate0)
     (drop_rank_r, drop_rank_e, drop_agg_r, drop_agg_e,
-     n_before, n_after) = ex_acc
+     n_before, n_after, live, shipped) = ex_acc
     return unpermute(buf), {
         "dropped_requests_rank": drop_rank_r,
         "dropped_elems_rank": drop_rank_e,
@@ -533,6 +595,8 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
         "dropped_elems_agg": drop_agg_e,
         "requests_before_coalesce": n_before,
         "requests_after_coalesce": n_after,
+        "slow_hop_live_elems": live,
+        "slow_hop_shipped_elems": shipped,
         "requests_at_ga": unpermute(lax.psum(dr_acc[0], (lagg_axis,))),
     }
 
@@ -564,24 +628,26 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
     strategy only, never routing).
     """
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
-    if not placement_mod.is_identity(placement):
-        perm = placement_mod.validate_placement(placement, n_dest)
-        # slot perm[g] serves domain g: hand it the domain's shard
-        file_shard = lax.ppermute(file_shard, node_axis,
-                                  [(s, perm[s]) for s in range(n_dest)])
-        slot_of = jnp.asarray(perm, jnp.int32)
-    else:
-        slot_of = None
-    eidx = jnp.arange(data_cap, dtype=jnp.int32)
-    req_of = segment_ids(r.lengths, data_cap)
-    fpos = r.offsets[req_of] + (eidx - starts[req_of])
-    live = eidx < jnp.sum(r.lengths, dtype=jnp.int32)
-    fpos = jnp.where(live, fpos, 0)
-    dest, wloc = fpos // dl, fpos % dl
+    with jax.named_scope(INDEX):
+        if not placement_mod.is_identity(placement):
+            perm = placement_mod.validate_placement(placement, n_dest)
+            # slot perm[g] serves domain g: hand it the domain's shard
+            file_shard = lax.ppermute(
+                file_shard, node_axis, [(s, perm[s]) for s in range(n_dest)])
+            slot_of = jnp.asarray(perm, jnp.int32)
+        else:
+            slot_of = None
+        eidx = jnp.arange(data_cap, dtype=jnp.int32)
+        req_of = segment_ids(r.lengths, data_cap)
+        fpos = r.offsets[req_of] + (eidx - starts[req_of])
+        live = eidx < jnp.sum(r.lengths, dtype=jnp.int32)
+        fpos = jnp.where(live, fpos, 0)
+        dest, wloc = fpos // dl, fpos % dl
 
     enc, dec, _ = _codec_hooks(slow_hop_codec, file_shard.dtype, (cb,),
                                fused=kernel_fusion == "fused_round")
 
+    @jax.named_scope(FETCH)
     def fetch(t):
         win = lax.dynamic_slice_in_dim(file_shard, t * cb, cb)
         if slow_hop_codec is None:
@@ -595,6 +661,7 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
             for p in parts)
         return (dec(gathered).astype(file_shard.dtype).reshape(-1))
 
+    @jax.named_scope(SCATTER)
     def scatter(t, out, allw):
         active = live & (wloc // cb == t)
         slot = dest if slot_of is None else slot_of[dest]

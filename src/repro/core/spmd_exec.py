@@ -82,6 +82,10 @@ def _write_shard_fn(plan: IOPlan, use_kernels: bool,
                 st["requests_before_coalesce"], (node, lagg)) // lmem_size,
             "requests_after_coalesce": lax.psum(
                 st["requests_after_coalesce"], (node, lagg)) // lmem_size,
+            "slow_hop_live_elems": lax.psum(
+                st["slow_hop_live_elems"], all_axes) // lmem_size,
+            "slow_hop_shipped_elems": lax.psum(
+                st["slow_hop_shipped_elems"], all_axes),
             "requests_at_ga": st["requests_at_ga"][None],
         }
         return shard[None], stats
@@ -92,13 +96,11 @@ def _write_shard_fn(plan: IOPlan, use_kernels: bool,
         slow_hop_codec=plan.slow_hop_codec,
         placement=plan.placement,
         kernel_fusion=plan.kernel_fusion)
-    stats = {
-        "dropped_requests": lax.psum(st["dropped_requests"],
-                                     (node, lagg, lmem)),
-        "dropped_elems": lax.psum(st["dropped_elems"],
-                                  (node, lagg, lmem)),
-        "requests_at_ga": st["requests_at_ga"][None],
-    }
+    all_axes = (node, lagg, lmem)
+    stats = {k: lax.psum(st[k], all_axes)
+             for k in ("dropped_requests", "dropped_elems",
+                       "slow_hop_live_elems", "slow_hop_shipped_elems")}
+    stats["requests_at_ga"] = st["requests_at_ga"][None]
     return shard[None], stats
 
 
@@ -137,6 +139,10 @@ def make_spmd_executor(mesh: jax.sharding.Mesh, plan: IOPlan,
 
     Write plans return ``(file [n_aggregators, domain_len] sharded over
     the slow axis, stats dict)``; read plans return per-rank payloads.
+    Both writes' stats hold ``slow_hop_live_elems`` (requested payload
+    elements the slow-axis ``all_to_all`` carries, each once however
+    many ranks hold a copy) and ``slow_hop_shipped_elems`` (elements it
+    moves: every rank's padded wire buckets, every round).
     The mesh's slow-axis size must match the plan's aggregator count —
     the plan IS the schedule, the mesh is just where it runs.
     """
@@ -152,6 +158,7 @@ def make_spmd_executor(mesh: jax.sharding.Mesh, plan: IOPlan,
             in_specs=(rank_spec, rank_spec, rank_spec, P(node)),
             out_specs=rank_spec)
     stats_spec = {"dropped_requests": P(), "dropped_elems": P(),
+                  "slow_hop_live_elems": P(), "slow_hop_shipped_elems": P(),
                   "requests_at_ga": P(node)}
     if plan.method == "tam":
         stats_spec.update({"requests_before_coalesce": P(),

@@ -2,8 +2,7 @@
 
 Two modes:
   --smoke      reduced config, real training on CPU (examples use this)
-  (default)    full config on the production mesh — requires hardware;
-               on this CPU container use launch.dryrun instead.
+  (default)    full config on the production mesh — requires hardware.
 
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch yi_34b --smoke \

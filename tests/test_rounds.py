@@ -2,10 +2,13 @@
 buffering, host-path round timing, cost-model wiring, and the SPMD
 byte-identity property (subprocess with 8 virtual devices) — including
 the pipelined (double-buffered) round loop and the domain-spanning
-request patterns. The pipelined overlap accounting and the optimal_cb
+request patterns — and the slow-hop counters of both writes on 1 and
+4 virtual devices. The pipelined overlap accounting and the optimal_cb
 autotuner live in tests/test_pipeline_model.py."""
+import json
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -168,3 +171,54 @@ def test_rounds_spmd_checks(spmd_env):
     assert "fuzz3/twophase/pl1_rle_k2_vs_ref" in proc.stdout
     assert "fuzz3/host/swap_rle_k2_vs_spmd" in proc.stdout
     assert "fuzz3/host/tam_swap_rle_k2_vs_spmd" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# slow-hop counters (subprocess per device count, tests/_slow_hop_run.py)
+# ---------------------------------------------------------------------------
+
+SLOW_HOP_RUN = Path(__file__).with_name("_slow_hop_run.py")
+
+
+@pytest.fixture(scope="module")
+def slow_hop_rows(spmd_env):
+    """``rows(n_devices)``: the script's rows, one process per device
+    count, run on first use."""
+    runs: dict = {}
+
+    def rows(n_devices):
+        if n_devices not in runs:
+            proc = subprocess.run(
+                [sys.executable, str(SLOW_HOP_RUN), str(n_devices)],
+                env=spmd_env, capture_output=True, text=True, timeout=300)
+            assert proc.returncode == 0, proc.stderr[-3000:]
+            runs[n_devices] = json.loads(
+                proc.stdout.strip().splitlines()[-1])
+        return runs[n_devices]
+
+    return rows
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("method", ["twophase", "tam"])
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_slow_hop_counters(slow_hop_rows, n_devices, method, depth):
+    (row,) = [r for r in slow_hop_rows(n_devices)
+              if (r["method"], r["depth"]) == (method, depth)]
+    st = row["stats"]
+    assert row["identical"]
+    assert st["dropped_requests"] == st["dropped_elems"] == 0
+    live, shipped = st["slow_hop_live_elems"], st["slow_hop_shipped_elems"]
+    # nothing dropped: every requested element crosses the slow hop once
+    assert live == row["requested_elems"]
+    assert live <= shipped
+    # rounds x ranks x destination buckets x bucket elements, where a
+    # bucket holds one window (cb = domain / 5) or the rank's payload
+    # (TAM: the lmem group's gathered window payload), whichever is less
+    ranks, nodes, lmem = {1: (1, 1, 1), 4: (4, 2, 2)}[n_devices]
+    reqs, unit, rounds = 16, 5, 5
+    cb = reqs * ranks * unit // nodes // rounds
+    bucket = min(reqs * unit, cb)
+    if method == "tam":
+        bucket = min(lmem * bucket, cb)
+    assert shipped == rounds * ranks * nodes * bucket

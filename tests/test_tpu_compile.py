@@ -3,8 +3,9 @@
 The TPU compiler is installed here, so every program below is compiled
 for a described (not attached) ``v5e:2x2`` topology: the collective
 write and read programs of ``chip_smoke.py`` at its per-round shapes
-(fewer rounds), on one chip and on the (2,1,2) mesh, must compile and
-fit a chip's 16 GiB. Nothing runs. The six Pallas kernels do not compile
+(fewer rounds), on one chip and on the (2,1,2) mesh, must compile,
+fit a chip's 16 GiB and keep every round-loop stage scope in the
+compiled ops' metadata. Nothing runs. The six Pallas kernels do not compile
 for v5e yet; each is a strict xfail naming what the compiler refuses, to
 be flipped as the kernels are fixed.
 
@@ -28,6 +29,11 @@ from repro.launch.mesh import make_io_mesh
 
 V5E_HBM_BYTES = 16 * 2**30
 ROUNDS = 2
+# the round-loop stage scopes (core/rounds.py) each program's ops carry
+WRITE_SCOPES = ("io.split", "io.select", "io.exchange", "io.drain",
+                "io.merge")
+SCOPES = {"twophase": WRITE_SCOPES, "tam": WRITE_SCOPES + ("io.stage1",),
+          "read": ("io.index", "io.fetch", "io.scatter")}
 
 
 @pytest.fixture(scope="module")
@@ -89,8 +95,11 @@ def test_main_path_compiles_for_v5e(topo, no_compile_cache, chips, program):
     per_device = (m.argument_size_in_bytes + m.output_size_in_bytes
                   + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert 0 < per_device < V5E_HBM_BYTES
+    text = compiled.as_text()
     if chips > 1:
-        assert "all-to-all" in compiled.as_text() or program == "read"
+        assert "all-to-all" in text or program == "read"
+    missing = [sc for sc in SCOPES[program] if f"/{sc}/" not in text]
+    assert not missing, f"stage scopes lost in compilation: {missing}"
 
 
 REQ_BLOCK = 1024
