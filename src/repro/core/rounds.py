@@ -114,12 +114,14 @@ re-split), ``io.exchange`` (bucketing, the codec's encode and the
 slow-axis ``all_to_all``), ``io.drain`` (decode, flatten, sort and
 pack of a received window), ``io.merge`` (the masked ``pmax`` merge
 and the accumulate at ``t * cb``); the read has ``io.index`` (the
-once-per-read element index), ``io.fetch`` (the window broadcast) and
-``io.scatter`` (placing the window's elements). The scopes are
-metadata only: they add no op. Both writes also count, per round, the
-requested payload elements the slow-axis ``all_to_all`` carries
-(``slow_hop_live_elems``) and the elements it moves, padded buckets of
-every wire part included (``slow_hop_shipped_elems``).
+once-per-read element index and window runs), ``io.fetch`` (the window
+broadcast) and ``io.scatter`` (placing the window's elements, under a
+nested ``sliced`` or ``full_pass`` scope naming the path the read
+took). The scopes are metadata only: they add no op. Both writes also
+count, per round, the requested payload elements the slow-axis
+``all_to_all`` carries (``slow_hop_live_elems``) and the elements it
+moves, padded buckets of every wire part included
+(``slow_hop_shipped_elems``).
 
 Cost-model coupling
 -------------------
@@ -136,6 +138,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -601,6 +604,62 @@ def exchange_rounds_write_tam(sched: RoundScheduler, node_axis: str,
     }
 
 
+class ReadWindowRuns(NamedTuple):
+    """Where each (domain, window) of a read lands in one rank's output
+    (:func:`read_window_runs`). Window ``k = g * n_rounds + t`` is
+    round ``t``'s window of domain ``g``: file positions
+    ``[k * cb, (k + 1) * cb)``.
+
+    fpos: int32[data_cap] — the file position of each output element;
+        ``file_len`` past the payload (window key ``n_windows``).
+    sliceable: bool scalar — file positions rise strictly over the
+        payload and stay inside the file (a list sorted by offset whose
+        requests are disjoint); only then does ``first`` describe the
+        output.
+    first: int32[n_windows + 1] — the output index of each window's
+        first element (``first[n_windows]`` is where the payload ends);
+        window k fills ``first[k + 1] - first[k]`` elements.
+    """
+
+    fpos: jax.Array
+    sliceable: jax.Array
+    first: jax.Array
+
+
+def read_slices_pay(sched: RoundScheduler, data_cap: int) -> bool:
+    """Whether the read's sliced scatter, ``n_aggregators`` slices of
+    ``min(cb, data_cap)`` elements per round, touches fewer elements
+    than a pass over the ``data_cap``-element output."""
+    return sched.n_aggregators * min(sched.cb, data_cap) < data_cap
+
+
+def read_window_runs(r: RequestList, starts: jax.Array,
+                     sched: RoundScheduler, data_cap: int
+                     ) -> ReadWindowRuns:
+    """The file position of each of one rank's read output elements,
+    and the run of the output each (domain, window) fills. Where file
+    positions rise strictly, a window's elements are one contiguous run
+    of at most ``min(cb, data_cap)`` elements (its positions are
+    distinct), found by a binary search of the window starts in the
+    positions. ``starts`` are the requests' payload starts
+    (``coalesce.request_starts``). Keep the predicate and the search on
+    ``fpos``: on a v5e, a second reader of the request list moved its
+    gather table out of fast memory and made the positions' gather
+    2.5x slower."""
+    cb, file_len = sched.cb, sched.layout.file_len
+    n_windows = sched.n_aggregators * sched.n_rounds
+    eidx = jnp.arange(data_cap, dtype=jnp.int32)
+    req_of = segment_ids(r.lengths, data_cap)
+    fpos = r.offsets[req_of] + (eidx - starts[req_of])
+    live = eidx < jnp.sum(r.lengths, dtype=jnp.int32)
+    fpos = jnp.where(live, fpos, file_len)
+    sliceable = (jnp.all(~live[1:] | (fpos[1:] > fpos[:-1]))
+                 & jnp.all(~live | ((fpos >= 0) & (fpos < file_len))))
+    first = jnp.searchsorted(
+        fpos, jnp.arange(n_windows + 1, dtype=jnp.int32) * cb)
+    return ReadWindowRuns(fpos, sliceable, first)
+
+
 def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
                          r: RequestList, starts: jax.Array,
                          file_shard: jax.Array, data_cap: int,
@@ -608,10 +667,11 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
                          depth: int | None = None,
                          slow_hop_codec: str | None = None,
                          placement=None,
-                         kernel_fusion: str | None = None) -> jax.Array:
+                         kernel_fusion: str | None = None, *,
+                         rank_axes: tuple[str, ...]) -> jax.Array:
     """Round loop of the collective read: per round, aggregators
     broadcast one ``cb``-sized window over the slow axis and every rank
-    gathers the elements of its requests falling in that window. Peak
+    places the elements of its requests falling in that window. Peak
     per-rank buffering is ``n_nodes * cb`` instead of ``file_len``.
     ``depth=k`` / ``pipeline=True`` run the window ring: the broadcast
     of window t overlaps the scatters of the k-1 carried older windows.
@@ -625,9 +685,39 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
     payloads are byte-identical for every placement.
     ``kernel_fusion="fused_round"`` swaps the rle decode scatter for
     the Pallas ``zero_skip_decode`` kernel (byte-identical; execution
-    strategy only, never routing).
+    strategy only, never routing). ``rank_axes`` are the mesh axes the
+    ranks span.
+
+    The scatter takes one of two paths, the same for the whole read:
+
+    * **sliced** (scope ``io.scatter/sliced``): MPI file views have
+      nondecreasing displacements, so a rank's flattened request list
+      is sorted by offset. Where its requests are also disjoint, file
+      positions rise along the output, and each (domain, window) fills
+      one contiguous run of it (:func:`read_window_runs`, once, in
+      ``io.index``). Round t places domain g's window through a
+      ``min(cb, data_cap)``-element slice of the output at that run's
+      start, so a round touches ``n_dest * min(cb, data_cap)``
+      elements, not ``data_cap``.
+    * **full pass** (scope ``io.scatter/full_pass``): every round
+      selects over the whole output.
+
+    The shapes pick first, at trace time: where the slices would touch
+    no fewer elements than the full pass (:func:`read_slices_pay`
+    false, e.g. one round of ``cb = domain_len`` over a per-rank output
+    no longer than ``n_dest * cb``), only the full pass is built. Else
+    one ``lax.cond`` outside the round loop picks, at run time, the
+    sliced path when every rank over ``rank_axes`` can take it, so all
+    ranks run the same collectives; a rank whose own read requests
+    overlap (the sorted lists MPI allows) takes every rank down the
+    full pass. Both paths return the same bytes. Whether a read took
+    the sliced path, and how full its slices are (``first[-1] -
+    first[0]`` ÷ ``n_windows * min(cb, data_cap)``), is
+    :func:`read_slices_pay` and :func:`read_window_runs` of the same
+    requests.
     """
     n_dest, cb, dl = sched.n_aggregators, sched.cb, sched.domain_len
+    n_rounds = sched.n_rounds
     with jax.named_scope(INDEX):
         if not placement_mod.is_identity(placement):
             perm = placement_mod.validate_placement(placement, n_dest)
@@ -636,13 +726,11 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
                 file_shard, node_axis, [(s, perm[s]) for s in range(n_dest)])
             slot_of = jnp.asarray(perm, jnp.int32)
         else:
+            perm = tuple(range(n_dest))
             slot_of = None
-        eidx = jnp.arange(data_cap, dtype=jnp.int32)
-        req_of = segment_ids(r.lengths, data_cap)
-        fpos = r.offsets[req_of] + (eidx - starts[req_of])
-        live = eidx < jnp.sum(r.lengths, dtype=jnp.int32)
-        fpos = jnp.where(live, fpos, 0)
-        dest, wloc = fpos // dl, fpos % dl
+        # the full pass alone reads only fpos: XLA drops the rest
+        runs = read_window_runs(r, starts, sched, data_cap)
+        fpos = runs.fpos
 
     enc, dec, _ = _codec_hooks(slow_hop_codec, file_shard.dtype, (cb,),
                                fused=kernel_fusion == "fused_round")
@@ -661,33 +749,67 @@ def exchange_rounds_read(sched: RoundScheduler, node_axis: str,
             for p in parts)
         return (dec(gathered).astype(file_shard.dtype).reshape(-1))
 
+    d = max(1, min(_effective_depth(pipeline, depth), n_rounds))
+
+    def run(scatter):
+        out0 = jnp.zeros((data_cap,), file_shard.dtype)
+        if d == 1:
+            return lax.fori_loop(
+                0, n_rounds, lambda t, out: scatter(t, out, fetch(t)), out0)
+        ring = tuple(fetch(i) for i in range(d - 1))    # prologue
+
+        def body(t, carry):
+            out, ring = carry
+            nxt = fetch(t)                           # broadcast window t …
+            out = scatter(t - (d - 1), out, ring[0])    # … place the oldest
+            return out, ring[1:] + (nxt,)
+
+        out, ring = lax.fori_loop(d - 1, n_rounds, body, (out0, ring))
+        for j in range(d - 1):                       # epilogue
+            out = scatter(n_rounds - (d - 1) + j, out, ring[j])
+        return out
+
+    def full_pass():
+        with jax.named_scope(INDEX):
+            live = (jnp.arange(data_cap, dtype=jnp.int32)
+                    < jnp.sum(r.lengths, dtype=jnp.int32))
+            dest, wloc = fpos // dl, fpos % dl
+
+        @jax.named_scope(SCATTER)
+        @jax.named_scope("full_pass")
+        def scatter(t, out, allw):
+            active = live & (wloc // cb == t)
+            slot = dest if slot_of is None else slot_of[dest]
+            src = slot * cb + (wloc - t * cb)
+            vals = allw[jnp.clip(src, 0, n_dest * cb - 1)]
+            return jnp.where(active, vals, out)
+
+        return run(scatter)
+
+    if not read_slices_pay(sched, data_cap):
+        return full_pass()
+
+    span = min(cb, data_cap)
+
     @jax.named_scope(SCATTER)
-    def scatter(t, out, allw):
-        active = live & (wloc // cb == t)
-        slot = dest if slot_of is None else slot_of[dest]
-        src = slot * cb + (wloc - t * cb)
-        vals = allw[jnp.clip(src, 0, n_dest * cb - 1)]
-        return jnp.where(active, vals, out)
+    @jax.named_scope("sliced")
+    def scatter_sliced(t, out, allw):
+        for g in range(n_dest):
+            k = g * n_rounds + t
+            a, b = runs.first[k], runs.first[k + 1]
+            s0 = jnp.clip(a, 0, data_cap - span)
+            e = s0 + jnp.arange(span, dtype=jnp.int32)
+            f = lax.dynamic_slice_in_dim(fpos, s0, span)
+            src = perm[g] * cb + (f - k * cb)
+            vals = allw[jnp.clip(src, 0, n_dest * cb - 1)]
+            cur = lax.dynamic_slice_in_dim(out, s0, span)
+            out = lax.dynamic_update_slice_in_dim(
+                out, jnp.where((e >= a) & (e < b), vals, cur), s0, 0)
+        return out
 
-    out0 = jnp.zeros((data_cap,), file_shard.dtype)
-    d = max(1, min(_effective_depth(pipeline, depth), sched.n_rounds))
-    if d == 1:
-        return lax.fori_loop(
-            0, sched.n_rounds,
-            lambda t, out: scatter(t, out, fetch(t)), out0)
-
-    ring = tuple(fetch(i) for i in range(d - 1))    # prologue
-
-    def body(t, carry):
-        out, ring = carry
-        nxt = fetch(t)                           # broadcast window t …
-        out = scatter(t - (d - 1), out, ring[0])    # … place the oldest
-        return out, ring[1:] + (nxt,)
-
-    out, ring = lax.fori_loop(d - 1, sched.n_rounds, body, (out0, ring))
-    for j in range(d - 1):                       # epilogue
-        out = scatter(sched.n_rounds - (d - 1) + j, out, ring[j])
-    return out
+    with jax.named_scope(INDEX):
+        sliced = lax.pmin(runs.sliceable.astype(jnp.int32), rank_axes) > 0
+    return lax.cond(sliced, lambda: run(scatter_sliced), full_pass)
 
 
 def peak_aggregator_buffer_elems(data_cap: int, n_nodes: int,

@@ -113,7 +113,8 @@ def _read_shard_fn(plan: IOPlan, offsets, lengths, count, file_shard):
         plan.data_cap, depth=plan.pipeline_depth,
         slow_hop_codec=plan.slow_hop_codec,
         placement=plan.placement,
-        kernel_fusion=plan.kernel_fusion)
+        kernel_fusion=plan.kernel_fusion,
+        rank_axes=plan.axis_names)
     return out[None]
 
 

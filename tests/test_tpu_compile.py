@@ -5,9 +5,10 @@ for a described (not attached) ``v5e:2x2`` topology: the collective
 write and read programs of ``chip_smoke.py`` at its per-round shapes
 (fewer rounds), on one chip and on the (2,1,2) mesh, must compile,
 fit a chip's 16 GiB and keep every round-loop stage scope in the
-compiled ops' metadata. Nothing runs. The six Pallas kernels do not compile
-for v5e yet; each is a strict xfail naming what the compiler refuses, to
-be flipped as the kernels are fixed.
+compiled ops' metadata, with the read's scatter paths (``sliced`` and
+``full_pass``) where its shapes build them. Nothing runs. The six Pallas
+kernels do not compile for v5e yet; each is a strict xfail naming what
+the compiler refuses, to be flipped as the kernels are fixed.
 
 The topology is described inside a fixture (never at import), so under
 several test workers only the worker given this file loads the TPU
@@ -34,6 +35,11 @@ WRITE_SCOPES = ("io.split", "io.select", "io.exchange", "io.drain",
                 "io.merge")
 SCOPES = {"twophase": WRITE_SCOPES, "tam": WRITE_SCOPES + ("io.stage1",),
           "read": ("io.index", "io.fetch", "io.scatter")}
+# the read's scatter paths per chip count: one chip's rank holds two
+# windows, so one slice per round pays and both paths are built; at
+# ROUNDS rounds a 2x2 rank holds one window, which two domains' slices
+# would cover twice, so the full pass is built alone
+READ_PATHS = {1: ("sliced", "full_pass"), 4: ("full_pass",)}
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +106,10 @@ def test_main_path_compiles_for_v5e(topo, no_compile_cache, chips, program):
         assert "all-to-all" in text or program == "read"
     missing = [sc for sc in SCOPES[program] if f"/{sc}/" not in text]
     assert not missing, f"stage scopes lost in compilation: {missing}"
+    if program == "read":
+        paths = tuple(p for p in ("sliced", "full_pass")
+                      if f"/io.scatter/{p}/" in text)
+        assert paths == READ_PATHS[chips]
 
 
 REQ_BLOCK = 1024
