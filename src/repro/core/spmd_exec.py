@@ -79,9 +79,9 @@ def _write_shard_fn(plan: IOPlan, use_kernels: bool,
                 + lax.psum(st["dropped_elems_agg"], all_axes)
                 // lmem_size,
             "requests_before_coalesce": lax.psum(
-                st["requests_before_coalesce"], (node, lagg)) // lmem_size,
+                st["requests_before_coalesce"], all_axes) // lmem_size,
             "requests_after_coalesce": lax.psum(
-                st["requests_after_coalesce"], (node, lagg)) // lmem_size,
+                st["requests_after_coalesce"], all_axes) // lmem_size,
             "slow_hop_live_elems": lax.psum(
                 st["slow_hop_live_elems"], all_axes) // lmem_size,
             "slow_hop_shipped_elems": lax.psum(

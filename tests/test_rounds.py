@@ -206,7 +206,7 @@ def test_slow_hop_counters(slow_hop_rows, n_devices, method, depth):
     (row,) = [r for r in slow_hop_rows(n_devices)
               if (r["method"], r["depth"]) == (method, depth)]
     st = row["stats"]
-    assert row["identical"]
+    assert row["identical"] and row["read_exact"]
     assert st["dropped_requests"] == st["dropped_elems"] == 0
     live, shipped = st["slow_hop_live_elems"], st["slow_hop_shipped_elems"]
     # nothing dropped: every requested element crosses the slow hop once
